@@ -153,7 +153,9 @@ def verify_checkpoint(path: str) -> dict:
 
 def load_checkpoint(path: str, like: Any):
     """Restore into the structure of ``like`` (a pytree template),
-    verifying the content checksum when present."""
+    verifying the content checksum when present.  Each array lands
+    where the template's leaf lives (its sharding, for a JAX array),
+    never staged through the default device."""
     arrays, _ = _read_bundle(path)
     flat, treedef = jax.tree_util.tree_flatten_with_path(like)
     leaves = []
@@ -165,7 +167,10 @@ def load_checkpoint(path: str, like: Any):
         except KeyError:
             raise CheckpointCorruptError(
                 f"checkpoint {path} is missing array {key!r}") from None
-        leaves.append(jax.numpy.asarray(arr, dtype=leaf.dtype))
+        arr = np.asarray(arr, dtype=leaf.dtype)
+        sharding = getattr(leaf, "sharding", None)
+        leaves.append(jax.device_put(arr, sharding) if sharding is not None
+                      else jax.numpy.asarray(arr))
     return jax.tree_util.tree_unflatten(treedef, leaves)
 
 

@@ -21,18 +21,20 @@ from .baselines import SaturnPolicy
 from .executor import simulate
 from .job import ClusterSpec, Job, ServeJob
 from .library import ParallelismLibrary
-from .profiler import HARDWARE, HardwareSpec, TrialRunner
+from .profiler import HardwareSpec, TrialRunner
 from .runtime import SimResult
 from .schedule import Policy
 
 
 class SaturnSession:
     def __init__(self, cluster: ClusterSpec,
-                 hardware: HardwareSpec = HARDWARE["a100"],
+                 hardware: Optional[HardwareSpec] = None,
                  cache_path: Optional[str] = None,
                  library: Optional[ParallelismLibrary] = None):
         self.cluster = cluster
         self.library = library or ParallelismLibrary()
+        # hardware=None plans with the local TPU's own spec (A100 off
+        # TPU); see profiler.local_hardware
         self.runner = TrialRunner(self.library, hardware, cache_path)
         # mixed fleets: derive per-class hardware (speed_hint-scaled
         # rates, per-class HBM) so trials land at realistic speeds

@@ -235,8 +235,8 @@ class LocalRunner:
         from ..checkpoint.store import load_training_state, save_checkpoint
         from ..data.synthetic import SyntheticLM
         from ..parallelism.build import BuiltJob
-        from .compile_cache import enable_persistent_compilation_cache
-        enable_persistent_compilation_cache()
+        from ..compile_cache import enable_compile_cache
+        enable_compile_cache()
 
         devs = (self.devices or jax.devices())[:n_devices]
         plan = technique.plan(job.cfg, n_devices)

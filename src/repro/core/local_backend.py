@@ -169,9 +169,9 @@ class LocalJaxBackend(ExecutionBackend):
     def bind(self, jobs, profiles, cluster: ClusterSpec) -> None:
         import jax
 
-        from .compile_cache import enable_persistent_compilation_cache
+        from ..compile_cache import enable_compile_cache
         super().bind(jobs, profiles, cluster)
-        enable_persistent_compilation_cache()
+        enable_compile_cache()
         self._jax_devices = list(self._devices or jax.devices())
         if cluster.total_gpus > len(self._jax_devices):
             raise RuntimeError(

@@ -176,6 +176,13 @@ class ProcessJaxBackend(LocalJaxBackend):
 
         import jax
 
+        if jax.default_backend() == "tpu":
+            # this process now holds the chips: a spawned worker could
+            # not open them and would hang or fail
+            raise RuntimeError(
+                "ProcessJaxBackend cannot run on a TPU host: the "
+                "coordinator process holds the TPU, so its worker "
+                "processes cannot open it; use backend='local'")
         # protocol grandparent: profile plumbing without the local
         # backend's in-process device checks (children own devices)
         from .runtime import ExecutionBackend
@@ -187,7 +194,6 @@ class ProcessJaxBackend(LocalJaxBackend):
             os.environ["XLA_FLAGS"] = (
                 cur + f" --xla_force_host_platform_device_count="
                 f"{cluster.total_gpus}").strip()
-        self._gpu = jax.default_backend() == "gpu"
         if self.ckpt_dir is None:
             self.ckpt_dir = tempfile.mkdtemp(prefix="saturn_proc_")
         os.makedirs(self.ckpt_dir, exist_ok=True)
@@ -343,7 +349,6 @@ class ProcessJaxBackend(LocalJaxBackend):
     def launch(self, job: Job, entry, placement, device_class, remaining,
                t, token) -> ProcHandle:
         ckpt = os.path.join(self.ckpt_dir, f"{job.name}.npz")
-        device_ids = list(placement.devices)
         spec = {
             "job_name": job.name,
             "model_cfg": job.cfg,
@@ -353,29 +358,17 @@ class ProcessJaxBackend(LocalJaxBackend):
             "lr": job.lr,
             "seed": job.seed,
             "technique": entry.technique,
-            "device_ids": (list(range(len(device_ids))) if self._gpu
-                           else device_ids),
+            "device_ids": list(placement.devices),
             "ckpt_path": ckpt,
             "steps_to_run": int(remaining),
             "ckpt_every_steps": self.ckpt_every_steps,
             "heartbeat_every_s": self.heartbeat_every_s,
         }
         parent_conn, child_conn = self._ctx.Pipe(duplex=True)
-        stash = os.environ.get("CUDA_VISIBLE_DEVICES")
-        if self._gpu:
-            os.environ["CUDA_VISIBLE_DEVICES"] = \
-                ",".join(str(d) for d in device_ids)
-        try:
-            process = self._ctx.Process(
-                target=_worker_main, args=(child_conn, spec),
-                name=f"saturn-proc-{job.name}", daemon=True)
-            process.start()
-        finally:
-            if self._gpu:
-                if stash is None:
-                    os.environ.pop("CUDA_VISIBLE_DEVICES", None)
-                else:
-                    os.environ["CUDA_VISIBLE_DEVICES"] = stash
+        process = self._ctx.Process(
+            target=_worker_main, args=(child_conn, spec),
+            name=f"saturn-proc-{job.name}", daemon=True)
+        process.start()
         child_conn.close()      # the child holds its own end now
         proc = _Proc(process, parent_conn, time.monotonic())
         try:

@@ -49,6 +49,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..compile_cache import enable_compile_cache
 from ..launch.hlo_analysis import analyze, link_seconds, scale_analysis
 from ..models.params import abstract_params, param_count
 from ..models.transformer import model_spec
@@ -68,13 +69,53 @@ class HardwareSpec:
 
 
 HARDWARE = {
-    # TPU v5e (production dry-run target)
-    "v5e": HardwareSpec("v5e", 197e12, 819e9, 50e9, 16e9),
+    # TPU v5e, per chip (Google Cloud documentation, "TPU v5e"): 197
+    # TFLOP/s bf16, 819 GB/s HBM, 16 GB HBM, 1,600 Gbit/s ICI
+    "v5e": HardwareSpec("v5e", 197e12, 819e9, 1600e9 / 8, 16e9),
     # A100-40GB (the paper's p4d.24xlarge nodes)
     "a100": HardwareSpec("a100", 312e12, 1555e9, 600e9 / 8, 40e9),
     # V100-16GB (p3.16xlarge) — the mixed-fleet second class
     "v100": HardwareSpec("v100", 125e12, 900e9, 300e9 / 8, 16e9),
 }
+
+
+# jax ``Device.device_kind`` -> HARDWARE row
+DEVICE_KINDS = {"TPU v5 lite": "v5e"}
+
+
+def hardware_for_kind(device_kind: str) -> HardwareSpec:
+    """The HARDWARE row of a JAX device kind; a kind not in the table is
+    an error, never a default."""
+    try:
+        return HARDWARE[DEVICE_KINDS[device_kind]]
+    except KeyError:
+        raise ValueError(
+            f"no HardwareSpec for device kind {device_kind!r}; known "
+            f"kinds: {sorted(DEVICE_KINDS)}") from None
+
+
+def local_hardware(hardware: Optional[HardwareSpec] = None
+                   ) -> HardwareSpec:
+    """The spec to plan with.  On TPU devices it is the local device's
+    own row, and an explicit ``hardware`` that contradicts it is an
+    error; elsewhere (CPU, virtual time) it is ``hardware``, A100 by
+    default."""
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return hardware or HARDWARE["a100"]
+    hw = hardware_for_kind(dev.device_kind)
+    if hardware is not None and hardware != hw:
+        raise ValueError(
+            f"hardware {hardware.name!r} contradicts the local "
+            f"{dev.device_kind!r} devices ({hw.name!r})")
+    return hw
+
+
+def is_out_of_memory(e: BaseException) -> bool:
+    """True for the device's (or its compiler's) out-of-memory error —
+    the one trial failure that makes a combo infeasible."""
+    return isinstance(e, jax.errors.JaxRuntimeError) and \
+        "RESOURCE_EXHAUSTED" in str(e)
 
 
 def hardware_for_class(base: HardwareSpec, device_class) -> HardwareSpec:
@@ -209,12 +250,12 @@ def fit_calibration(device_class: str, points, mode: str
 
 class TrialRunner:
     def __init__(self, library: ParallelismLibrary,
-                 hardware: HardwareSpec = HARDWARE["a100"],
+                 hardware: Optional[HardwareSpec] = None,
                  cache_path: Optional[str] = None,
                  flush_every: int = 16,
                  hardware_by_class: Optional[Dict[str, HardwareSpec]] = None):
         self.library = library
-        self.hw = hardware
+        self.hw = hardware = local_hardware(hardware)
         # per-device-class hardware: the reference spec under "default";
         # register_class / hardware_by_class add mixed-fleet entries
         self.hw_by_class: Dict[str, HardwareSpec] = {DEFAULT_CLASS: hardware}
@@ -234,15 +275,10 @@ class TrialRunner:
         self._analysis_cache: Dict[Tuple, Dict[str, float]] = {}
         # per-device-class roofline calibration (persisted in the cache)
         self.calibration: Dict[str, ClassCalibration] = {}
-        if cache_path:
-            # real compiles during trials hit the persistent XLA cache,
-            # keyed alongside this profile cache
-            from .compile_cache import enable_persistent_compilation_cache
-            enable_persistent_compilation_cache(
-                os.path.join(os.path.dirname(os.path.abspath(cache_path)),
-                             "xla-cache"))
-            if os.path.exists(cache_path):
-                self._load_cache(cache_path)
+        # trials compile the steps the backends run later
+        enable_compile_cache()
+        if cache_path and os.path.exists(cache_path):
+            self._load_cache(cache_path)
 
     def register_class(self, device_class) -> HardwareSpec:
         """Register a :class:`~repro.core.job.DeviceClass`, deriving its
@@ -601,18 +637,13 @@ class TrialRunner:
                 params, opt, _ = built.step(params, opt, batch)
             jax.block_until_ready(params)
             dt = (time.perf_counter() - t0) / 2
-        except (AssertionError, ValueError, TypeError, ZeroDivisionError,
-                RuntimeError) as e:
-            # a trial that cannot even build/run its step for THIS
-            # job's concrete shape (e.g. pipeline microbatching vs the
-            # batch size) is an infeasible choice, not a crashed sweep
-            # — exactly what a real cluster trial would conclude
-            print(f"trial {job.name}/{technique}x{n_devices} failed "
-                  f"({e!r}); recording infeasible")
-            return Profile(job.name, technique, n_devices, float("inf"),
-                           float("inf"), False, "empirical",
-                           {"trial_error": 1.0},
-                           device_class=device_class)
+        except jax.errors.JaxRuntimeError as e:
+            # a step that does not fit the device is an infeasible
+            # choice; any other failure is a bug and propagates
+            if not is_out_of_memory(e):
+                raise
+            return self._out_of_memory(job, technique, n_devices,
+                                       "empirical", device_class)
         mem = self._mem_estimate(job, plan)
         return Profile(job.name, technique, n_devices, dt, mem,
                        mem <= hw.hbm_capacity, "empirical",
@@ -621,11 +652,31 @@ class TrialRunner:
     # ---------------------------------------------------------- analytic
     def _profile_analytic(self, job: Job, technique: str, n_devices: int,
                           hw: HardwareSpec, device_class: str) -> Profile:
+        """Lower + compile the real step and read cost_analysis / HLO
+        collectives.  A mesh the local devices cannot host falls back to
+        the napkin model, labelled ``"napkin"``."""
         tech = self.library.get(technique)
         plan = tech.plan(job.cfg, n_devices)
-        return self._finish(job, technique, n_devices,
-                            self._roofline_terms(job, plan, hw),
-                            "analytic", hw, device_class)
+        if n_devices > len(jax.devices()):
+            return self._finish(job, technique, n_devices,
+                                self._roofline_napkin(job, plan, hw),
+                                "napkin", hw, device_class)
+        try:
+            terms = self._roofline_from_compile(job, plan, hw)
+        except jax.errors.JaxRuntimeError as e:
+            if not is_out_of_memory(e):
+                raise
+            return self._out_of_memory(job, technique, n_devices,
+                                       "analytic", device_class)
+        return self._finish(job, technique, n_devices, terms, "analytic",
+                            hw, device_class)
+
+    @staticmethod
+    def _out_of_memory(job: Job, technique: str, n_devices: int,
+                       source: str, device_class: str) -> Profile:
+        return Profile(job.name, technique, n_devices, float("inf"),
+                       float("inf"), False, source, {"out_of_memory": 1.0},
+                       device_class=device_class)
 
     def _profile_napkin(self, job: Job, technique: str, n_devices: int,
                         hw: HardwareSpec, device_class: str) -> Profile:
@@ -670,16 +721,6 @@ class TrialRunner:
         if plan.remat:
             return 2.0 * b * s * cfg.d_model * layers  # one residual/layer
         return per_layer * layers
-
-    def _roofline_terms(self, job: Job, plan: Plan,
-                        hw: HardwareSpec) -> Dict[str, float]:
-        """Lower + compile the real step on a placeholder mesh and read
-        cost_analysis / HLO collectives.  Falls back to a napkin model if
-        the local device pool can't host the mesh."""
-        try:
-            return self._roofline_from_compile(job, plan, hw)
-        except Exception:
-            return self._roofline_napkin(job, plan, hw)
 
     # ------------------------------------------------ compile memoization
     def _shape_key(self, job: Job, technique: str, mesh_shape) -> Tuple:
@@ -752,7 +793,7 @@ class TrialRunner:
         bytes_acc = float(cost.get("bytes accessed", 0.0)) / n
         coll = collective_bytes_from_hlo(compiled.as_text())
         coll_bytes = coll["total"] / n
-        mem = self._compiled_mem(compiled) or self._mem_estimate(job, plan)
+        mem = self._compiled_mem(compiled)
         return {
             "compute_s": flops / hw.flops,
             "memory_s": bytes_acc / hw.hbm_bw,
@@ -763,14 +804,11 @@ class TrialRunner:
         }
 
     @staticmethod
-    def _compiled_mem(compiled) -> Optional[float]:
-        try:
-            ma = compiled.memory_analysis()
-            return float(ma.temp_size_in_bytes + ma.argument_size_in_bytes +
-                         ma.output_size_in_bytes) / max(
-                             len(compiled.devices()), 1)
-        except Exception:
-            return None
+    def _compiled_mem(compiled) -> float:
+        """Bytes per device of the compiled (per-device SPMD) program."""
+        ma = compiled.memory_analysis()
+        return float(ma.temp_size_in_bytes + ma.argument_size_in_bytes +
+                     ma.output_size_in_bytes)
 
     def _utilization(self, job: Job, plan: Plan) -> float:
         """MXU/SM utilization model: saturates with per-device tokens;
@@ -922,8 +960,9 @@ class TrialRunner:
                 else tech.plan(job.cfg, n)
             try:
                 return n, self._hlo_analysis(job, base_plan)
-            except Exception:
-                continue
+            except jax.errors.JaxRuntimeError as e:
+                if not is_out_of_memory(e):
+                    raise
         return None
 
     def _predict_roofline(self, job: Job, technique: str, n_devices: int,

@@ -56,7 +56,9 @@ class SyntheticLM:
     def batches(self, batch: int, seq: int, *, dtype=jnp.float32,
                 num_batches: Optional[int] = None,
                 skip: int = 0) -> Iterator[dict]:
-        """Yield device batches.  ``skip`` fast-forwards the stream past
+        """Yield host (numpy) batches; the consumer places them on its
+        own devices (``BuiltJob.place_batch``), so no batch is staged on
+        the default device.  ``skip`` fast-forwards the stream past
         that many batches first (checkpoint resume: a run continued from
         step k must see batch k next, not batch 0 again)."""
         rng = np.random.RandomState(self.seed + 1)
@@ -65,10 +67,8 @@ class SyntheticLM:
         i = 0
         while num_batches is None or i < num_batches:
             raw = self._raw_batch(rng, batch, seq)
-            out = {k: jnp.asarray(v, dtype if v.dtype.kind == "f"
-                                  else jnp.int32)
+            yield {k: v.astype(dtype if v.dtype.kind == "f" else np.int32)
                    for k, v in raw.items()}
-            yield out
             i += 1
 
 

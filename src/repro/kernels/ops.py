@@ -1,11 +1,12 @@
 """Jit'd public wrappers for the Pallas TPU kernels.
 
-``kernel_opts(cfg)`` builds the ``opts`` dict consumed by the model
-layer (``forward(..., opts=...)``): on TPU backends it routes the
-attention / RG-LRU / mLSTM hot-spots through the Pallas kernels; on CPU
-(this container) the pure-jnp blockwise paths are used unless
-``interpret=True`` is forced (tests do this to execute the kernel
-bodies).
+``kernel_opts(cfg)`` builds an ``opts`` dict for the model layer
+(``forward(..., opts=...)``) that would route the attention / RG-LRU /
+mLSTM hot-spots through the Pallas kernels.  It is not on the training
+path yet: ``BuiltJob`` builds its step without ``opts``, and the kernels
+have no backward pass, so every trained or profiled step runs the
+pure-jnp blockwise paths.  Off TPU, ``interpret=True`` executes the
+kernel bodies (the tests do this).
 """
 from __future__ import annotations
 

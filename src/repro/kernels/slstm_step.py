@@ -8,8 +8,8 @@ resident in VMEM across the whole sequence:
 
 Grid: (batch, heads, num_s_blocks) — s minor-most, so each (b, h)
 program walks its sequence blocks in order; gate pre-activations stream
-in (block_s, D, 4) tiles; per step a (1, D) x (D, D) matmul per gate
-runs on the MXU from VMEM.
+in (4, block_s, D) tiles, read 8 time steps at a time; per step a
+(1, D) x (D, D) matmul per gate runs on the MXU from VMEM.
 """
 from __future__ import annotations
 
@@ -20,9 +20,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+_SUBLANES = 8
+
 
 def _slstm_kernel(gates_ref, rz_ref, ri_ref, rf_ref, ro_ref, y_ref,
-                  c_ref, n_ref, m_ref, h_ref, *, block_s: int):
+                  c_ref, n_ref, m_ref, h_ref, *, block_s: int, d: int):
     isb = pl.program_id(2)
 
     @pl.when(isb == 0)
@@ -36,30 +38,40 @@ def _slstm_kernel(gates_ref, rz_ref, ri_ref, rf_ref, ro_ref, y_ref,
     ri = ri_ref[0].astype(jnp.float32)
     rf = rf_ref[0].astype(jnp.float32)
     ro = ro_ref[0].astype(jnp.float32)
-    g = gates_ref[0, 0].astype(jnp.float32)  # (block_s, D, 4)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (_SUBLANES, d), 0)
 
-    def step(t, state):
+    def tile(j, state):
+        # Mosaic loads and stores whole (8, D) tiles at dynamic offsets;
+        # the 8 time steps inside a tile index the loaded values
+        # statically
+        span = pl.ds(pl.multiple_of(j * _SUBLANES, _SUBLANES), _SUBLANES)
+        gz, gi, gf, go = (gates_ref[0, 0, e, span, :].astype(jnp.float32)
+                          for e in range(4))          # each (8, D)
+        y = jnp.zeros((_SUBLANES, d), jnp.float32)
         c, n, m, h = state                   # each (1, D) f32
-        # recurrent contribution: pres_e = gx_e + sum_d h_d R[e, d]
-        hz = jax.lax.dot_general(h, rz, (((1,), (1,)), ((), ())))
-        hi = jax.lax.dot_general(h, ri, (((1,), (1,)), ((), ())))
-        hf = jax.lax.dot_general(h, rf, (((1,), (1,)), ((), ())))
-        ho = jax.lax.dot_general(h, ro, (((1,), (1,)), ((), ())))
-        z = jnp.tanh(g[t, :, 0][None] + hz)
-        i_pre = g[t, :, 1][None] + hi
-        lf = jax.nn.log_sigmoid(g[t, :, 2][None] + hf)
-        o = jax.nn.sigmoid(g[t, :, 3][None] + ho)
-        m_new = jnp.maximum(lf + m, i_pre)
-        fg = jnp.exp(lf + m - m_new)
-        ig = jnp.exp(i_pre - m_new)
-        c_new = fg * c + ig * z
-        n_new = jnp.maximum(fg * n + ig, 1e-6)
-        h_new = o * c_new / n_new
-        y_ref[0, 0, t, :] = h_new[0].astype(y_ref.dtype)
-        return (c_new, n_new, m_new, h_new)
+        for t in range(_SUBLANES):
+            # recurrent contribution: pres_e = gx_e + sum_d h_d R[e, d]
+            hz = jax.lax.dot_general(h, rz, (((1,), (1,)), ((), ())))
+            hi = jax.lax.dot_general(h, ri, (((1,), (1,)), ((), ())))
+            hf = jax.lax.dot_general(h, rf, (((1,), (1,)), ((), ())))
+            ho = jax.lax.dot_general(h, ro, (((1,), (1,)), ((), ())))
+            z = jnp.tanh(gz[t:t + 1] + hz)
+            i_pre = gi[t:t + 1] + hi
+            lf = jax.nn.log_sigmoid(gf[t:t + 1] + hf)
+            o = jax.nn.sigmoid(go[t:t + 1] + ho)
+            m_new = jnp.maximum(lf + m, i_pre)
+            fg = jnp.exp(lf + m - m_new)
+            ig = jnp.exp(i_pre - m_new)
+            c = fg * c + ig * z
+            n = jnp.maximum(fg * n + ig, 1e-6)
+            m = m_new
+            h = o * c / n
+            y = jnp.where(rows == t, h, y)
+        y_ref[0, 0, span, :] = y.astype(y_ref.dtype)
+        return c, n, m, h
 
     state = (c_ref[...], n_ref[...], m_ref[...], h_ref[...])
-    c, n, m, h = jax.lax.fori_loop(0, block_s, step, state)
+    c, n, m, h = jax.lax.fori_loop(0, block_s // _SUBLANES, tile, state)
     c_ref[...], n_ref[...], m_ref[...], h_ref[...] = c, n, m, h
 
 
@@ -72,15 +84,15 @@ def slstm_step_scan(gates, rz, ri, rf, ro, *, block_s: int = 128,
     ``repro.models.recurrent.slstm_block``."""
     b, s, h, d, _ = gates.shape
     block_s = min(block_s, s)
-    assert s % block_s == 0
-    gt = gates.transpose(0, 2, 1, 3, 4)      # (B, H, S, D, 4)
+    assert s % block_s == 0 and block_s % _SUBLANES == 0
+    gt = gates.transpose(0, 2, 4, 1, 3)      # (B, H, 4, S, D)
     grid = (b, h, s // block_s)
     out = pl.pallas_call(
-        functools.partial(_slstm_kernel, block_s=block_s),
+        functools.partial(_slstm_kernel, block_s=block_s, d=d),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, block_s, d, 4),
-                         lambda b_, h_, isb: (b_, h_, isb, 0, 0)),
+            pl.BlockSpec((1, 1, 4, block_s, d),
+                         lambda b_, h_, isb: (b_, h_, 0, isb, 0)),
             pl.BlockSpec((1, d, d), lambda b_, h_, isb: (h_, 0, 0)),
             pl.BlockSpec((1, d, d), lambda b_, h_, isb: (h_, 0, 0)),
             pl.BlockSpec((1, d, d), lambda b_, h_, isb: (h_, 0, 0)),

@@ -29,9 +29,6 @@ def main():
     ap.add_argument("--ckpt", default="")
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--log-every", type=int, default=10)
-    ap.add_argument("--use-kernels", action="store_true",
-                    help="route hot-spots through the Pallas kernels "
-                         "(TPU backend; interpret on CPU)")
     args = ap.parse_args()
 
     import jax

@@ -45,11 +45,19 @@ class BuiltJob:
             with axis_rules(plan.rules, mesh):
                 return base(params, opt_state, batch)
 
-        metric_sh = NamedSharding(self.mesh, PartitionSpec())
+        options = None
+        if plan.remat and plan.n_devices > 1 and \
+                self.mesh.devices.flat[0].platform == "tpu":
+            # libtpu's latency-hiding scheduler emits an invalid schedule
+            # (RET_CHECK in hlo_schedule.cc) for a rematerialized layer
+            # scan around the mLSTM chunk loop with the batch sharded
+            # over several chips; such steps compile without it
+            options = {"xla_tpu_enable_latency_hiding_scheduler": "false"}
         return jax.jit(
             step,
             in_shardings=(self.p_sh, self.o_sh, self._batch_sh_tree()),
             out_shardings=(self.p_sh, self.o_sh, None),
+            compiler_options=options,
         )
 
     def _batch_axis(self):

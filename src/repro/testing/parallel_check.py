@@ -1,8 +1,11 @@
 """Parallelism-equivalence checker.
 
-Runs in a subprocess with XLA_FLAGS=--xla_force_host_platform_device_count=8
-(so the main pytest process keeps 1 device): for each technique, one real
-train step on 8 virtual devices must match the single-device baseline.
+For each technique valid at ``n_devices``, one real train step on the
+first ``n_devices`` devices must match the single-device baseline.  On
+the CPU it runs in a subprocess with
+XLA_FLAGS=--xla_force_host_platform_device_count=8 (so the main pytest
+process keeps 1 device); ``chip_smoke.py --chips 4`` runs it on a TPU
+host at full width.
 
 Usage: python -m repro.testing.parallel_check [arch_id]
 """
@@ -17,16 +20,15 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 
-def check(arch_id: str = "h2o-danube-3-4b", n_devices: int = 8,
-          tol: float = 2e-2) -> int:
-    from repro.configs import concrete_batch, get_config
+def check(cfg, n_devices: int, tol: float = 2e-2) -> int:
+    """Returns the number of techniques whose step did not match."""
+    from repro.configs import concrete_batch
     from repro.models.transformer import init_model
     from repro.optim.adamw import AdamWConfig, init_opt_state
     from repro.parallelism.build import BuiltJob
     from repro.parallelism.techniques import DEFAULT_TECHNIQUES
     from repro.train.steps import make_train_step
 
-    cfg = get_config(arch_id).reduced(num_layers=4)
     opt_cfg = AdamWConfig(lr=1e-3, warmup_steps=1, total_steps=10)
     key = jax.random.PRNGKey(42)
     batch = concrete_batch(cfg, 8, 32)
@@ -37,12 +39,12 @@ def check(arch_id: str = "h2o-danube-3-4b", n_devices: int = 8,
     p_ref, o_ref, m_ref = jax.jit(make_train_step(cfg, opt_cfg))(
         params0, opt0, batch)
     ref_loss = float(m_ref["loss"])
-    print(f"[baseline] {arch_id} loss={ref_loss:.6f}")
+    print(f"[baseline] {cfg.name} loss={ref_loss:.6f}")
 
     failures = 0
     for tech in DEFAULT_TECHNIQUES:
         if not tech.search_space(cfg, n_devices):
-            print(f"[{tech.name}] not in search space for {arch_id}@{n_devices} — skipped")
+            print(f"[{tech.name}] not in search space for {cfg.name}@{n_devices} — skipped")
             continue
         plan = tech.plan(cfg, n_devices)
         job = BuiltJob(cfg, plan, opt_cfg)
@@ -63,5 +65,6 @@ def check(arch_id: str = "h2o-danube-3-4b", n_devices: int = 8,
 
 
 if __name__ == "__main__":
+    from repro.configs import get_config
     arch = sys.argv[1] if len(sys.argv) > 1 else "h2o-danube-3-4b"
-    sys.exit(check(arch))
+    sys.exit(check(get_config(arch).reduced(num_layers=4), 8))

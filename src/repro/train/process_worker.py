@@ -42,29 +42,6 @@ import threading
 import time
 
 
-def _enable_compile_cache() -> None:
-    """Persistent XLA cache (same dir contract as
-    ``repro.core.compile_cache``, inlined so the child skips the heavy
-    ``repro.core`` import): relaunching onto a previously compiled
-    (model, technique, slice) choice is a disk hit, not a recompile."""
-    import jax
-    d = os.environ.get(
-        "SATURN_COMPILE_CACHE",
-        os.path.join(os.path.expanduser("~"), ".cache", "saturn", "xla"))
-    try:
-        os.makedirs(d, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", os.path.abspath(d))
-    except (AttributeError, ValueError, OSError):
-        return
-    for knob, val in (
-            ("jax_persistent_cache_min_compile_time_secs", 0.0),
-            ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(knob, val)
-        except (AttributeError, ValueError):
-            pass
-
-
 def _worker_main(conn, spec: dict) -> None:
     """Process target.  Any exception is reported over the pipe as an
     ``error`` message and the process exits 3 — the coordinator treats
@@ -137,7 +114,8 @@ def _run(conn, spec: dict) -> None:
 
     import jax
 
-    _enable_compile_cache()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from repro.checkpoint.store import load_training_state, save_checkpoint
     from repro.data.synthetic import SyntheticLM
     from repro.optim.adamw import AdamWConfig

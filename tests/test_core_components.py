@@ -1,5 +1,9 @@
 """Parallelism Library registry, Trial Runner, checkpoint store, data
 pipeline, MoE routing properties."""
+import os
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,7 +13,9 @@ from _hypothesis_compat import given, settings, st
 from repro.configs import get_config
 from repro.core.job import Job
 from repro.core.library import ParallelismLibrary
-from repro.core.profiler import HARDWARE, TrialRunner, collective_bytes_from_hlo
+from repro.core.profiler import (HARDWARE, TrialRunner,
+                                 collective_bytes_from_hlo,
+                                 hardware_for_kind, local_hardware)
 from repro.parallelism.base import Plan, Technique
 
 
@@ -85,6 +91,148 @@ def test_infeasible_technique_marked():
     job = Job("i", get_config("xlstm-125m").reduced(), 2, 32, 10)
     prof = runner.profile(job, "tp", 7)  # 4 heads % 7 != 0
     assert not prof.feasible
+
+
+# -------------------------------------------- device hardware, trial errors
+
+class _FakeDevice:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
+def test_hardware_for_device_kind():
+    assert hardware_for_kind("TPU v5 lite") is HARDWARE["v5e"]
+    with pytest.raises(ValueError, match="no HardwareSpec"):
+        hardware_for_kind("TPU v99")
+
+
+@pytest.mark.parametrize("kind,given,expect", [
+    ("TPU v5 lite", None, "v5e"),
+    ("TPU v5 lite", "v5e", "v5e"),
+    ("TPU v5 lite", "a100", ValueError),    # contradicts the device
+    ("TPU v99", None, ValueError),          # unknown kind
+])
+def test_local_hardware_on_tpu(monkeypatch, kind, given, expect):
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a: [_FakeDevice("tpu", kind)])
+    hw = HARDWARE[given] if given else None
+    if expect is ValueError:
+        with pytest.raises(ValueError):
+            local_hardware(hw)
+    else:
+        assert local_hardware(hw) is HARDWARE[expect]
+
+
+def test_local_hardware_off_tpu_keeps_given_spec():
+    assert local_hardware() is HARDWARE["a100"]
+    assert local_hardware(HARDWARE["v100"]) is HARDWARE["v100"]
+
+
+def _tiny_job():
+    return Job("e", get_config("xlstm-125m").reduced(), 2, 32, 10)
+
+
+def _raise(err):
+    def fn(*a, **k):
+        raise err
+    return fn
+
+
+class _FailingBuilt:
+    """A BuiltJob whose step raises ``err`` (an empirical trial)."""
+
+    def __init__(self, err):
+        self.err = err
+
+    def init(self, key):
+        return {}, {}
+
+    def place_batch(self, batch):
+        return batch
+
+    def step(self, *args):
+        raise self.err
+
+
+OOM = jax.errors.JaxRuntimeError(
+    "RESOURCE_EXHAUSTED: Ran out of memory in memory space hbm.")
+
+
+@pytest.mark.parametrize("mode", ["analytic", "empirical"])
+def test_out_of_memory_trial_is_infeasible(monkeypatch, mode):
+    runner = TrialRunner(ParallelismLibrary(), HARDWARE["a100"])
+    monkeypatch.setattr(runner, "_compiled_step", _raise(OOM))
+    monkeypatch.setattr(runner, "_built_job",
+                        lambda job, plan: _FailingBuilt(OOM))
+    prof = runner.profile(_tiny_job(), "ddp", 1, mode=mode)
+    assert not prof.feasible and prof.source == mode
+    assert prof.step_time_s == float("inf")
+    assert prof.terms == {"out_of_memory": 1.0}
+
+
+@pytest.mark.parametrize("mode", ["analytic", "empirical"])
+@pytest.mark.parametrize("err", [
+    jax.errors.JaxRuntimeError("INTERNAL: RET_CHECK failure"),
+    ValueError("bad shape"),
+])
+def test_other_trial_errors_raise(monkeypatch, mode, err):
+    runner = TrialRunner(ParallelismLibrary(), HARDWARE["a100"])
+    monkeypatch.setattr(runner, "_compiled_step", _raise(err))
+    monkeypatch.setattr(runner, "_built_job",
+                        lambda job, plan: _FailingBuilt(err))
+    with pytest.raises(type(err)):
+        runner.profile(_tiny_job(), "ddp", 1, mode=mode)
+
+
+def test_analytic_beyond_local_devices_is_labelled_napkin(monkeypatch):
+    runner = TrialRunner(ParallelismLibrary(), HARDWARE["a100"])
+    monkeypatch.setattr(runner, "_compiled_step",
+                        _raise(AssertionError("must not compile")))
+    prof = runner.profile(_tiny_job(), "fsdp", len(jax.devices()) + 1,
+                          mode="analytic")
+    assert prof.source == "napkin" and prof.feasible
+    assert 0 < prof.step_time_s < float("inf")
+
+
+# ------------------------------------------------------ compile cache
+
+@pytest.fixture
+def restore_cache_dir():
+    from jax.experimental.compilation_cache import compilation_cache
+    before = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", before)
+    compilation_cache.reset_cache()
+
+
+def test_compile_cache_default_is_in_checkout(monkeypatch,
+                                              restore_cache_dir):
+    from repro import compile_cache
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert compile_cache.CACHE_DIR == os.path.join(root, ".jax_cache")
+    assert compile_cache.enable_compile_cache() == compile_cache.CACHE_DIR
+    assert jax.config.jax_compilation_cache_dir == compile_cache.CACHE_DIR
+    with open(os.path.join(root, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
+
+
+def test_compile_cache_honours_env(monkeypatch, tmp_path,
+                                   restore_cache_dir):
+    from repro import compile_cache
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.enable_compile_cache() == str(tmp_path)
+    jax.jit(lambda x: x * 3 + 1)(jnp.arange(7.0)).block_until_ready()
+    assert os.listdir(tmp_path), "the compile did not land in the env dir"
+
+
+def test_worker_imports_cache_without_scheduler():
+    """Worker processes enable the cache without importing repro.core."""
+    code = ("import sys, repro.compile_cache, repro.train.process_worker; "
+            "sys.exit(any(m.startswith('repro.core') for m in sys.modules))")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
 
 
 def test_collective_regex_parser():

@@ -57,6 +57,18 @@ def baseline(tmp_path_factory):
 
 
 @pytest.mark.slow
+def test_bind_refuses_tpu_before_spawning(monkeypatch):
+    """A coordinator on a TPU host holds the chips: bind must fail loudly
+    instead of spawning workers that cannot open them."""
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    be = ProcessJaxBackend()
+    jobs, profiles = mk_jobs()
+    with pytest.raises(RuntimeError, match="TPU"):
+        be.bind(jobs, profiles, CLUSTER)
+    assert not hasattr(be, "_monitor_thread")
+
+
 def test_process_backend_trains_for_real(tmp_path):
     """Two jobs really train in separate OS processes through the
     Schedule IR: exact step budgets, real finite losses, checkpoints on
