@@ -126,7 +126,8 @@ def sweep(cfg, chips: int, *, batches=SWEEP_BATCHES, seq: int = SWEEP_SEQ,
         state = "feasible" if p.feasible else \
             f"infeasible {sorted(p.terms)}"
         print(f"  profile {name} {tech}x{g}: {p.step_time_s:.4f} s/step "
-              f"source={p.source} {state}", flush=True)
+              f"{p.mem_per_device / 1e9:.2f} GB/chip source={p.source} "
+              f"{state}", flush=True)
     t0 = time.perf_counter()
     with tempfile.TemporaryDirectory(prefix="saturn_smoke_") as ckpt:
         res = sess.run(backend="local", ckpt_dir=ckpt,

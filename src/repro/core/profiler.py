@@ -629,12 +629,13 @@ class TrialRunner:
             params, opt = built.init(jax.random.PRNGKey(0))
             batch = built.place_batch(
                 concrete_batch(job.cfg, job.batch_size, job.seq_len))
-            # 1 warmup (compile) + 2 timed minibatches, per the paper
-            params, opt, _ = built.step(params, opt, batch)
+            step = self._compiled_step(job, plan, (params, opt, batch))
+            # 1 warmup + 2 timed minibatches, per the paper
+            params, opt, _ = step(params, opt, batch)
             jax.block_until_ready(params)
             t0 = time.perf_counter()
             for _ in range(2):
-                params, opt, _ = built.step(params, opt, batch)
+                params, opt, _ = step(params, opt, batch)
             jax.block_until_ready(params)
             dt = (time.perf_counter() - t0) / 2
         except jax.errors.JaxRuntimeError as e:
@@ -644,7 +645,7 @@ class TrialRunner:
                 raise
             return self._out_of_memory(job, technique, n_devices,
                                        "empirical", device_class)
-        mem = self._mem_estimate(job, plan)
+        mem = self._compiled_mem(step)
         return Profile(job.name, technique, n_devices, dt, mem,
                        mem <= hw.hbm_capacity, "empirical",
                        device_class=device_class)
@@ -732,9 +733,8 @@ class TrialRunner:
                 job.seq_len, technique, tuple(mesh_shape))
 
     def _built_job(self, job: Job, plan: Plan) -> BuiltJob:
-        """Memoized BuiltJob per shape key — repeat empirical trials of
-        shape-identical jobs reuse the step fn (and its jit cache)
-        instead of re-lowering per job."""
+        """Memoized BuiltJob per shape key: shape-identical jobs share
+        one mesh, one set of shardings and one step function."""
         key = self._shape_key(job, plan.technique, plan.mesh_shape)
         with self._lock:
             built = self._built_cache.get(key)
@@ -745,10 +745,15 @@ class TrialRunner:
                 self._built_cache.setdefault(key, built)
         return built
 
-    def _compiled_step(self, job: Job, plan: Plan):
+    def _compiled_step(self, job: Job, plan: Plan, args=None):
         """Memoized ``lower().compile()`` of the real step per
-        ⟨job-shape, technique, mesh-shape⟩, shared by the analytic
-        roofline, the HLO analyzer, and the roofline strategy."""
+        ⟨job-shape, technique, mesh-shape⟩, shared by empirical trials,
+        the analytic roofline, the HLO analyzer, and the roofline
+        strategy.  An empirical trial passes its concrete ``(params,
+        opt_state, batch)``: lowered from those, the program is the one
+        the execution backends compile, so they find it in the
+        persistent compilation cache (the abstract lowering numbers its
+        functions differently and misses)."""
         key = self._shape_key(job, plan.technique, plan.mesh_shape)
         with self._lock:
             compiled = self._compile_cache.get(key)
@@ -759,15 +764,17 @@ class TrialRunner:
         if n > len(jax.devices()):
             raise RuntimeError("not enough local devices to lower")
         built = self._built_job(job, plan)
-        spec = model_spec(job.cfg)
-        p_abs = abstract_params(spec, jnp.float32)
-        o_abs = {"mu": abstract_params(spec, jnp.float32),
-                 "nu": abstract_params(spec, jnp.float32),
-                 "step": jax.ShapeDtypeStruct((), jnp.int32)}
-        batch = jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
-            concrete_batch(job.cfg, job.batch_size, job.seq_len))
-        compiled = built.step.lower(p_abs, o_abs, batch).compile()
+        if args is None:
+            spec = model_spec(job.cfg)
+            p_abs = abstract_params(spec, jnp.float32)
+            o_abs = {"mu": abstract_params(spec, jnp.float32),
+                     "nu": abstract_params(spec, jnp.float32),
+                     "step": jax.ShapeDtypeStruct((), jnp.int32)}
+            batch = jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+                concrete_batch(job.cfg, job.batch_size, job.seq_len))
+            args = (p_abs, o_abs, batch)
+        compiled = built.step.lower(*args).compile()
         with self._lock:
             self._compile_cache.setdefault(key, compiled)
         return compiled

@@ -5,10 +5,11 @@ Grid: (batch, num_r_blocks, num_s_blocks) with the sequence dimension
 minor-most: each (b, ir) program walks its sequence blocks in order,
 carrying h in VMEM scratch.  Inside a block a ``fori_loop`` walks
 (8, block_r) tiles, the unit Mosaic loads and stores at a dynamic
-offset, and steps through each tile's 8 time steps on (1, block_r)
-vectors — elementwise VPU work; there is no MXU component, so the kernel's job is purely to keep
-the carry resident in VMEM and stream a/b through HBM exactly once
-(the associative-scan reference does log(S) passes over HBM instead).
+offset, and steps through each tile's 8 time steps, indexed statically,
+on (1, block_r) vectors — elementwise VPU work; there is no MXU
+component, so the kernel's job is purely to keep the carry resident in
+VMEM and stream a/b through HBM exactly once (the associative-scan
+reference does log(S) passes over HBM instead).
 """
 from __future__ import annotations
 
@@ -33,9 +34,6 @@ def _rglru_kernel(a_ref, b_ref, y_ref, h_ref, *, block_s: int,
     rows = jax.lax.broadcasted_iota(jnp.int32, (_SUBLANES, block_r), 0)
 
     def tile(j, h):
-        # Mosaic loads and stores whole (8, block_r) tiles at dynamic
-        # offsets; the 8 time steps inside a tile index the loaded value
-        # statically
         span = pl.ds(pl.multiple_of(j * _SUBLANES, _SUBLANES), _SUBLANES)
         a = a_ref[0, span, :].astype(jnp.float32)
         b = b_ref[0, span, :].astype(jnp.float32)

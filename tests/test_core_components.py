@@ -75,14 +75,19 @@ def test_profiler_napkin_monotonic_and_cached(tmp_path):
     assert runner2.profile(job, "fsdp", 8).step_time_s == p8.step_time_s
 
 
-def test_profiler_empirical_single_device():
+def test_profiler_empirical_single_device(monkeypatch):
     lib = ParallelismLibrary()
     runner = TrialRunner(lib, HARDWARE["a100"])
+    # a trial's memory is the compiled program's, never the napkin model's
+    monkeypatch.setattr(runner, "_mem_estimate",
+                        _raise(AssertionError("estimate used")))
     job = Job("e", get_config("xlstm-125m").reduced(), 2, 32, 10)
     prof = runner.profile(job, "ddp", 1, mode="empirical")
     assert prof.source == "empirical"
     assert prof.step_time_s > 0
     assert prof.feasible
+    compiled = runner._compiled_step(job, lib.get("ddp").plan(job.cfg, 1))
+    assert prof.mem_per_device == runner._compiled_mem(compiled) > 0
 
 
 def test_infeasible_technique_marked():

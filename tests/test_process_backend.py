@@ -56,7 +56,6 @@ def baseline(tmp_path_factory):
     return trajectory(res, "j0")
 
 
-@pytest.mark.slow
 def test_bind_refuses_tpu_before_spawning(monkeypatch):
     """A coordinator on a TPU host holds the chips: bind must fail loudly
     instead of spawning workers that cannot open them."""
@@ -69,6 +68,7 @@ def test_bind_refuses_tpu_before_spawning(monkeypatch):
     assert not hasattr(be, "_monitor_thread")
 
 
+@pytest.mark.slow
 def test_process_backend_trains_for_real(tmp_path):
     """Two jobs really train in separate OS processes through the
     Schedule IR: exact step budgets, real finite losses, checkpoints on
