@@ -38,6 +38,8 @@ from typing import Any, Optional
 import jax
 import numpy as np
 
+from .. import tracing
+
 # npz entry under which the JSON metadata (incl. checksum) is bundled;
 # the name cannot collide with pytree paths (they never start with "__")
 META_KEY = "__saturn_meta__"
@@ -80,10 +82,13 @@ def _atomic_write(path: str, write_fn) -> None:
     os.close(fd)
     try:
         with open(tmp, "wb") as f:
-            write_fn(f)
-            f.flush()
-            os.fsync(f.fileno())
-        os.replace(tmp, path)
+            with tracing.span("checkpoint.write"):
+                write_fn(f)
+                f.flush()
+            with tracing.span("checkpoint.fsync"):
+                os.fsync(f.fileno())
+        with tracing.span("checkpoint.rotate"):
+            os.replace(tmp, path)
     finally:
         if os.path.exists(tmp):
             os.remove(tmp)
@@ -100,14 +105,19 @@ def save_checkpoint(path: str, tree: Any, metadata: Optional[dict] = None,
     last-known-good fallback.
     """
     os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
-    arrays = _flatten_with_paths(tree)
+    with tracing.span("checkpoint.fetch"):
+        arrays = _flatten_with_paths(tree)
+    tracing.count("checkpoint.bytes",
+                  sum(a.nbytes for a in arrays.values()))
     meta = dict(metadata or {})
-    meta["checksum"] = _content_checksum(arrays)
+    with tracing.span("checkpoint.hash"):
+        meta["checksum"] = _content_checksum(arrays)
     payload = dict(arrays)
     payload[META_KEY] = np.frombuffer(
         json.dumps(meta).encode(), dtype=np.uint8)
     if keep_previous and os.path.exists(path):
-        os.replace(path, path + ".prev")
+        with tracing.span("checkpoint.rotate"):
+            os.replace(path, path + ".prev")
     _atomic_write(path, lambda f: np.savez(f, **payload))
     if metadata is not None:
         # convenience sidecar (atomic too); the bundled copy is
@@ -213,8 +223,10 @@ def load_training_state(path: str, params: Any, opt: Any):
         if not os.path.exists(p):
             continue
         try:
-            meta = verify_checkpoint(p)
-            state = load_checkpoint(p, like)
+            with tracing.span("restore.verify"):
+                meta = verify_checkpoint(p)
+            with tracing.span("restore.read"):
+                state = load_checkpoint(p, like)
         except CheckpointCorruptError as e:
             warnings.warn(
                 f"skipping corrupt checkpoint: {e}; "

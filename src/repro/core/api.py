@@ -17,6 +17,7 @@ from __future__ import annotations
 import dataclasses
 from typing import List, Optional, Sequence, Union
 
+from .. import tracing
 from .baselines import SaturnPolicy
 from .executor import simulate
 from .job import ClusterSpec, Job, ServeJob
@@ -127,15 +128,16 @@ class SaturnSession:
         Real trials fan out across ``workers`` threads (auto by default;
         empirical trials always run serially).
         """
-        self.profiles = self.runner.profile_all(
-            self.jobs,
-            self.gpu_counts(dense=(strategy in ("interpolate",
-                                                "roofline"))),
-            mode=mode, strategy=strategy, workers=workers,
-            calibration_trials=calibration_trials,
-            confidence_threshold=confidence_threshold,
-            classes=(self.cluster.device_classes if self.cluster.hetero
-                     else None))
+        with tracing.span("profile", mode=mode, strategy=strategy):
+            self.profiles = self.runner.profile_all(
+                self.jobs,
+                self.gpu_counts(dense=(strategy in ("interpolate",
+                                                    "roofline"))),
+                mode=mode, strategy=strategy, workers=workers,
+                calibration_trials=calibration_trials,
+                confidence_threshold=confidence_threshold,
+                classes=(self.cluster.device_classes if self.cluster.hetero
+                         else None))
         return self.profiles
 
     # ------------------------------------------------------ Solver + exec

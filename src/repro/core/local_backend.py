@@ -32,6 +32,7 @@ import threading
 import time
 from typing import Dict, List, Optional, Tuple
 
+from .. import tracing
 from .job import ClusterSpec, Job
 from .library import ParallelismLibrary
 from .perfmodel import ObservedProfiles, profile_key
@@ -44,13 +45,17 @@ class _Worker(threading.Thread):
     The engine-facing surface is tiny and lock-free (reads of ints and
     floats under the GIL): ``steps_done`` advances as steps retire,
     ``stop_flag`` requests a checkpoint-and-exit, ``done`` flips when
-    the segment is over (naturally or preempted).  The first step after
-    (re)launch is the JIT compile and is timed separately — it must not
-    poison the measured step rate (the profile-feedback channel).
+    the segment is over (naturally or preempted).  The segment runs in
+    one ``segment`` span, child of the engine's ``launch`` span, and
+    times itself on its spans' clock: the first step after (re)launch
+    loads or compiles the step program and is kept apart
+    (``first_step_s``) — it must not poison the measured step rate (the
+    profile-feedback channel).
     """
 
     def __init__(self, backend: "LocalJaxBackend", job: Job, technique,
-                 devices: List, ckpt_path: str, steps_to_run: int):
+                 devices: List, ckpt_path: str, steps_to_run: int,
+                 launch_span: Optional[tracing.Span] = None):
         super().__init__(daemon=True,
                          name=f"saturn-local-{job.name}")
         self.backend = backend
@@ -65,7 +70,9 @@ class _Worker(threading.Thread):
         self.done = threading.Event()
         self.error: Optional[BaseException] = None
         self.preempted = False
-        self.compile_s = 0.0
+        self.launch_span = launch_span
+        self.segment: Optional[tracing.Span] = None
+        self.first_step_s: Optional[float] = None
         self.finish_clock: Optional[float] = None
         self.losses: List[Tuple[int, float]] = []   # (absolute step, loss)
         self._dt_sum = 0.0
@@ -73,14 +80,30 @@ class _Worker(threading.Thread):
 
     @property
     def measured_step_s(self) -> Optional[float]:
-        """Mean post-compile step time; None until 2 steps retired."""
+        """Mean step after the first, ``place_batch`` through the loss's
+        sync; None until 2 steps retired."""
         if self._dt_n < 1:
             return None
         return self._dt_sum / self._dt_n
 
+    @property
+    def compile_s(self) -> float:
+        """Seconds this segment spent tracing, lowering, loading and
+        compiling programs (its ``compile.*`` counters)."""
+        seg = self.segment
+        return tracing.compile_seconds(seg.tree_counts) if seg else 0.0
+
+    @property
+    def span_totals(self) -> Dict[str, Dict[str, float]]:
+        """Per span name in this segment: count and seconds."""
+        return tracing.span_totals(self.segment) if self.segment else {}
+
     def run(self) -> None:
+        ln = self.launch_span
         try:
-            self._train()
+            with tracing.span("segment", parent=ln,
+                              **(ln.attrs if ln else {})) as self.segment:
+                self._train()
         except BaseException as e:          # surfaced by the engine
             self.error = e
         finally:
@@ -94,33 +117,44 @@ class _Worker(threading.Thread):
         from ..checkpoint.store import load_training_state, save_checkpoint
         from ..data.synthetic import SyntheticLM
 
-        built = self.backend._built_job(self.job, self.technique,
-                                        self.devices)
-        params, opt = built.init(jax.random.PRNGKey(self.job.seed))
-        params, opt, self.start_step = load_training_state(
-            self.ckpt_path, params, opt)
-        data = SyntheticLM(self.job.cfg, seed=self.job.seed).batches(
+        with tracing.span("build"):
+            built = self.backend._built_job(self.job, self.technique,
+                                            self.devices)
+        with tracing.span("init"):
+            params, opt = built.init(jax.random.PRNGKey(self.job.seed))
+        with tracing.span("restore"):
+            params, opt, self.start_step = load_training_state(
+                self.ckpt_path, params, opt)
+        data = iter(SyntheticLM(self.job.cfg, seed=self.job.seed).batches(
             self.job.batch_size, self.job.seq_len,
-            num_batches=self.steps_to_run, skip=self.start_step)
+            num_batches=self.steps_to_run, skip=self.start_step))
         loss = float("nan")
-        for b in data:
+        while True:
+            with tracing.span("step.data"):
+                b = next(data, None)
+            if b is None:
+                break
             if self.stop_flag.is_set():
                 self.preempted = True
                 break
-            t0 = time.perf_counter()
-            params, opt, m = built.step(params, opt, built.place_batch(b))
-            loss = float(m.get("loss", float("nan")))   # forces sync
-            dt = time.perf_counter() - t0
+            with tracing.span("step.place") as place:
+                batch = built.place_batch(b)
+            with tracing.span("step.dispatch"):
+                params, opt, m = built.step(params, opt, batch)
+            with tracing.span("step.sync") as sync:
+                loss = float(m.get("loss", float("nan")))  # the one sync
+            dt = sync.t1 - place.t0
             if self.steps_done == 0:
-                self.compile_s = dt
+                self.first_step_s = dt
             else:
                 self._dt_sum += dt
                 self._dt_n += 1
             self.steps_done += 1
             self.losses.append((self.start_step + self.steps_done, loss))
-        save_checkpoint(self.ckpt_path, {"params": params, "opt": opt},
-                        {"step": self.start_step + self.steps_done,
-                         "loss": loss})
+        with tracing.span("checkpoint"):
+            save_checkpoint(self.ckpt_path, {"params": params, "opt": opt},
+                            {"step": self.start_step + self.steps_done,
+                             "loss": loss})
 
 
 class LocalHandle(LaunchHandle):
@@ -307,22 +341,24 @@ class LocalJaxBackend(ExecutionBackend):
     # ------------------------------------------------------ run lifecycle
     def launch(self, job, entry, placement, device_class, remaining, t,
                token) -> LocalHandle:
-        devs = [self._jax_devices[d] for d in placement.devices]
-        ckpt = os.path.join(self.ckpt_dir, f"{job.name}.npz")
-        worker = _Worker(self, job, self.library.get(entry.technique),
-                         devs, ckpt, remaining)
-        try:
-            est = self.est_step(job.name, entry.technique, entry.n_gpus,
-                                device_class)
-        except KeyError:
-            est = self.fallback_step_s
-        if not math.isfinite(est) or est <= 0:
-            est = self.fallback_step_s
-        h = LocalHandle(worker, job, entry.technique, entry.n_gpus,
-                        placement, t, est, remaining, token)
-        with self._lock:
-            self._by_worker[worker] = h
-        worker.start()
+        with tracing.span("launch", job=job.name, technique=entry.technique,
+                          chips=entry.n_gpus, token=token) as sp:
+            devs = [self._jax_devices[d] for d in placement.devices]
+            ckpt = os.path.join(self.ckpt_dir, f"{job.name}.npz")
+            worker = _Worker(self, job, self.library.get(entry.technique),
+                             devs, ckpt, remaining, launch_span=sp)
+            try:
+                est = self.est_step(job.name, entry.technique, entry.n_gpus,
+                                    device_class)
+            except KeyError:
+                est = self.fallback_step_s
+            if not math.isfinite(est) or est <= 0:
+                est = self.fallback_step_s
+            h = LocalHandle(worker, job, entry.technique, entry.n_gpus,
+                            placement, t, est, remaining, token)
+            with self._lock:
+                self._by_worker[worker] = h
+            worker.start()
         return h
 
     def eta(self, handle: LocalHandle) -> float:
@@ -420,8 +456,12 @@ class LocalJaxBackend(ExecutionBackend):
             "steps": getattr(w, "raw_steps", w.steps_done),
             "preempted": preempted,
             "failed": error,
+            # tracing, lowering, loading and compiling programs; the
+            # whole first step (program load included) apart
             "compile_s": w.compile_s,
+            "first_step_s": w.first_step_s,
             "measured_step_s": w.measured_step_s,
+            "spans": w.span_totals,
             "first_loss": w.losses[0][1] if w.losses else None,
             "last_loss": w.losses[-1][1] if w.losses else None,
         }

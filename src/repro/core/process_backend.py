@@ -63,7 +63,8 @@ class _Proc:
     """Coordinator-side record of one worker process: the supervision
     state the monitor thread maintains plus a ``_Worker``-compatible
     stats surface (``steps_done`` / ``start_step`` / ``losses`` /
-    ``measured_step_s`` / ``compile_s`` / ``preempted`` /
+    ``measured_step_s`` / ``compile_s`` / ``first_step_s`` /
+    ``span_totals`` / ``preempted`` /
     ``finish_clock`` / ``done``) so the feedback and accounting
     plumbing inherited from :class:`LocalJaxBackend` applies as-is."""
 
@@ -89,6 +90,8 @@ class _Proc:
         self.exit_msg: Optional[dict] = None
         self.preempted = False
         self.compile_s = 0.0
+        self.first_step_s: Optional[float] = None
+        self.span_totals: dict = {}
         self.losses: List[Tuple[int, float]] = []
         self.finish_clock: Optional[float] = None
         self.done = threading.Event()
@@ -262,6 +265,8 @@ class ProcessJaxBackend(LocalJaxBackend):
             p.exit_msg = m
             p.preempted = bool(m.get("preempted"))
             p.compile_s = float(m.get("compile_s") or 0.0)
+            p.first_step_s = m.get("first_step_s")
+            p.span_totals = m.get("spans") or {}
             p.losses = [(int(s), float(v)) for s, v in m.get("losses", [])]
             p.finish_clock = self.now()
             p.done.set()

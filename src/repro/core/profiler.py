@@ -49,6 +49,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import tracing
 from ..compile_cache import enable_compile_cache
 from ..launch.hlo_analysis import analyze, link_seconds, scale_analysis
 from ..models.params import abstract_params, param_count
@@ -342,15 +343,18 @@ class TrialRunner:
                            device_class=device_class)
             ran_trial = False
         else:
-            if mode == "empirical":
-                prof = self._profile_empirical(job, technique, n_devices,
-                                               hw, device_class)
-            elif mode == "napkin":
-                prof = self._profile_napkin(job, technique, n_devices,
-                                            hw, device_class)
-            else:
-                prof = self._profile_analytic(job, technique, n_devices,
-                                              hw, device_class)
+            with tracing.span("trial", job=job.name, technique=technique,
+                              chips=n_devices, mode=mode):
+                tracing.count("trial.runs")
+                if mode == "empirical":
+                    prof = self._profile_empirical(job, technique, n_devices,
+                                                   hw, device_class)
+                elif mode == "napkin":
+                    prof = self._profile_napkin(job, technique, n_devices,
+                                                hw, device_class)
+                else:
+                    prof = self._profile_analytic(job, technique, n_devices,
+                                                  hw, device_class)
             ran_trial = True
         with self._lock:
             self._cache[key] = prof
@@ -624,20 +628,23 @@ class TrialRunner:
                 f"empirical profiling needs {n_devices} local devices")
         tech = self.library.get(technique)
         try:
-            plan = tech.plan(job.cfg, n_devices)
-            built = self._built_job(job, plan)
-            params, opt = built.init(jax.random.PRNGKey(0))
-            batch = built.place_batch(
-                concrete_batch(job.cfg, job.batch_size, job.seq_len))
-            step = self._compiled_step(job, plan, (params, opt, batch))
-            # 1 warmup + 2 timed minibatches, per the paper
-            params, opt, _ = step(params, opt, batch)
-            jax.block_until_ready(params)
-            t0 = time.perf_counter()
-            for _ in range(2):
+            with tracing.span("trial.init"):
+                plan = tech.plan(job.cfg, n_devices)
+                built = self._built_job(job, plan)
+                params, opt = built.init(jax.random.PRNGKey(0))
+                batch = built.place_batch(
+                    concrete_batch(job.cfg, job.batch_size, job.seq_len))
+            with tracing.span("trial.compile"):
+                step = self._compiled_step(job, plan, (params, opt, batch))
+            with tracing.span("trial.steps"):
+                # 1 warmup + 2 timed minibatches, per the paper
                 params, opt, _ = step(params, opt, batch)
-            jax.block_until_ready(params)
-            dt = (time.perf_counter() - t0) / 2
+                jax.block_until_ready(params)
+                t0 = time.perf_counter()
+                for _ in range(2):
+                    params, opt, _ = step(params, opt, batch)
+                jax.block_until_ready(params)
+                dt = (time.perf_counter() - t0) / 2
         except jax.errors.JaxRuntimeError as e:
             # a step that does not fit the device is an infeasible
             # choice; any other failure is a bug and propagates
@@ -675,6 +682,7 @@ class TrialRunner:
     @staticmethod
     def _out_of_memory(job: Job, technique: str, n_devices: int,
                        source: str, device_class: str) -> Profile:
+        tracing.count("trial.refused")
         return Profile(job.name, technique, n_devices, float("inf"),
                        float("inf"), False, source, {"out_of_memory": 1.0},
                        device_class=device_class)

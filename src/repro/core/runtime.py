@@ -51,6 +51,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from .. import tracing
 from .chaos import (CapacityChange, ChaosTrace, NodeFailure, NodeRecovery,
                     RetryPolicy, SpotGrant, SpotRevoke, WorkerFailure,
                     WorkerFault)
@@ -670,52 +671,54 @@ def execute_runtime(jobs: List[Job], policy: Policy,
 
     def replan(preempt: bool):
         nonlocal order, replans, restarts
-        live = state.live_jobs()
-        if not live:
-            return
-        if fleets is not None and \
-                backend.capacity() - fleets.held() <= 0:
-            return          # serving holds every device: nothing to plan
-        # warm-start-capable policies get the previous schedule, the
-        # current time and the running set and may re-solve only the
-        # residual; the default delegates to plan() unchanged.  Real
-        # backends hand over measured step times where observed.
-        order = Schedule.coerce(policy.plan_incremental(
-            live, dict(state.remaining), planning_profiles(),
-            planning_cluster(), dict(state.current_assign), prev=order,
-            now_s=state.t, running=frozenset(state.running)))
-        replans += 1
-        tel = getattr(order, "telemetry", None)
-        if tel is not None:     # which engine planned, at what cost
-            solver_log.append({**tel, "t": state.t})
-        if preempt:
-            new_assign = order.assignment_map()
-            for name in list(state.running):
-                if name in new_assign and \
-                        new_assign[name] != state.current_assign.get(name):
-                    h = state.running.pop(name)
-                    done = exec_backend.preempt(h, state.t)
-                    backend.release(h.placement)
-                    state.log_run(name, h, state.t)
-                    if done >= h.steps_at_start:
-                        # a real worker can finish its whole budget
-                        # while the replan solve was running: that is a
-                        # completion, not a restart (unreachable in
-                        # virtual time — a sim completion event always
-                        # fires before its job reaches this branch)
-                        state.remaining[name] = 0
-                        continue
-                    # checkpoint + relaunch penalty: the job is only
-                    # admissible again when RestartDone fires
-                    state.gantt.append(GanttEntry(
-                        name, "restart", 0, state.t,
-                        state.t + cluster.restart_cost_s, kind="restart",
-                        device_class=h.device_class))
-                    state.remaining[name] = max(1, h.steps_at_start - done)
-                    state.restarting.add(name)
-                    q.push(RestartDone(
-                        state.t + cluster.restart_cost_s, name))
-                    restarts += 1
+        # the policy's call and the preemptions it makes
+        with tracing.span("replan", preempt=preempt):
+            live = state.live_jobs()
+            if not live:
+                return
+            if fleets is not None and \
+                    backend.capacity() - fleets.held() <= 0:
+                return          # serving holds every device: nothing to plan
+            # warm-start-capable policies get the previous schedule, the
+            # current time and the running set and may re-solve only the
+            # residual; the default delegates to plan() unchanged.  Real
+            # backends hand over measured step times where observed.
+            order = Schedule.coerce(policy.plan_incremental(
+                live, dict(state.remaining), planning_profiles(),
+                planning_cluster(), dict(state.current_assign), prev=order,
+                now_s=state.t, running=frozenset(state.running)))
+            replans += 1
+            tel = getattr(order, "telemetry", None)
+            if tel is not None:     # which engine planned, at what cost
+                solver_log.append({**tel, "t": state.t})
+            if preempt:
+                new_assign = order.assignment_map()
+                for name in list(state.running):
+                    if name in new_assign and \
+                            new_assign[name] != state.current_assign.get(name):
+                        h = state.running.pop(name)
+                        done = exec_backend.preempt(h, state.t)
+                        backend.release(h.placement)
+                        state.log_run(name, h, state.t)
+                        if done >= h.steps_at_start:
+                            # a real worker can finish its whole budget
+                            # while the replan solve was running: that is a
+                            # completion, not a restart (unreachable in
+                            # virtual time — a sim completion event always
+                            # fires before its job reaches this branch)
+                            state.remaining[name] = 0
+                            continue
+                        # checkpoint + relaunch penalty: the job is only
+                        # admissible again when RestartDone fires
+                        state.gantt.append(GanttEntry(
+                            name, "restart", 0, state.t,
+                            state.t + cluster.restart_cost_s, kind="restart",
+                            device_class=h.device_class))
+                        state.remaining[name] = max(1, h.steps_at_start - done)
+                        state.restarting.add(name)
+                        q.push(RestartDone(
+                            state.t + cluster.restart_cost_s, name))
+                        restarts += 1
 
     def kill_launches(victims: set, t: float) -> None:
         """Kill every launch touching a victim device, salvaging its

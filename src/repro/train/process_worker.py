@@ -114,6 +114,7 @@ def _run(conn, spec: dict) -> None:
 
     import jax
 
+    from repro import tracing
     from repro.compile_cache import enable_compile_cache
     enable_compile_cache()
     from repro.checkpoint.store import load_training_state, save_checkpoint
@@ -130,58 +131,63 @@ def _run(conn, spec: dict) -> None:
     opt_cfg = AdamWConfig(lr=spec["lr"],
                           warmup_steps=min(100, total // 10 + 1),
                           total_steps=total)
-    built = BuiltJob(cfg, plan, opt_cfg, devices=devices)
-    params, opt = built.init(jax.random.PRNGKey(spec["seed"]))
-    params, opt, start_step = load_training_state(
-        spec["ckpt_path"], params, opt)
-    # the durable checkpoint is authoritative: never run past the job's
-    # total budget even when the coordinator's view lagged behind it
-    steps_to_run = max(0, min(spec["steps_to_run"], total - start_step))
-    send({"msg": "hello", "start_step": start_step,
-          "steps_to_run": steps_to_run})
+    # the segment's spans give its compile seconds and per-span totals
+    with tracing.span("segment", technique=spec["technique"],
+                      chips=len(devices)) as seg:
+        built = BuiltJob(cfg, plan, opt_cfg, devices=devices)
+        params, opt = built.init(jax.random.PRNGKey(spec["seed"]))
+        params, opt, start_step = load_training_state(
+            spec["ckpt_path"], params, opt)
+        # the durable checkpoint is authoritative: never run past the job's
+        # total budget even when the coordinator's view lagged behind it
+        steps_to_run = max(0, min(spec["steps_to_run"], total - start_step))
+        send({"msg": "hello", "start_step": start_step,
+              "steps_to_run": steps_to_run})
 
-    data = SyntheticLM(cfg, seed=spec["seed"]).batches(
-        spec["batch_size"], spec["seq_len"],
-        num_batches=steps_to_run, skip=start_step)
-    ckpt_every = int(spec.get("ckpt_every_steps", 0))
-    loss = float("nan")
-    compile_s = 0.0
-    dt_sum, dt_n = 0.0, 0
-    preempted = False
-    for b in data:
-        if stop.is_set():
-            preempted = True
-            break
-        while hang.is_set():        # wedged for real: silent AND stuck
-            time.sleep(0.05)
-        t0 = time.perf_counter()
-        params, opt, m = built.step(params, opt, built.place_batch(b))
-        loss = float(m.get("loss", float("nan")))   # forces sync
-        dt = time.perf_counter() - t0
-        if state["steps"] == 0:
-            compile_s = dt
-        else:
-            dt_sum += dt
-            dt_n += 1
-        state["steps"] += 1
-        losses.append((start_step + state["steps"], loss))
-        if ckpt_every and state["steps"] % ckpt_every == 0 \
-                and state["steps"] < steps_to_run:
-            step_abs = start_step + state["steps"]
-            save_checkpoint(spec["ckpt_path"],
-                            {"params": params, "opt": opt},
-                            {"step": step_abs, "loss": loss})
-            # the ack flushes pending loss records: every step at or
-            # below a durable checkpoint is then recorded parent-side,
-            # so a later crash loses no trajectory (steps PAST the
-            # checkpoint are replayed from it on resume)
-            send_with_losses({"msg": "ckpt", "step": step_abs})
-    step_abs = start_step + state["steps"]
-    save_checkpoint(spec["ckpt_path"], {"params": params, "opt": opt},
-                    {"step": step_abs, "loss": loss})
+        data = SyntheticLM(cfg, seed=spec["seed"]).batches(
+            spec["batch_size"], spec["seq_len"],
+            num_batches=steps_to_run, skip=start_step)
+        ckpt_every = int(spec.get("ckpt_every_steps", 0))
+        loss = float("nan")
+        first_step_s = None
+        dt_sum, dt_n = 0.0, 0
+        preempted = False
+        for b in data:
+            if stop.is_set():
+                preempted = True
+                break
+            while hang.is_set():        # wedged for real: silent AND stuck
+                time.sleep(0.05)
+            t0 = time.perf_counter()
+            params, opt, m = built.step(params, opt, built.place_batch(b))
+            loss = float(m.get("loss", float("nan")))   # forces sync
+            dt = time.perf_counter() - t0
+            if state["steps"] == 0:
+                first_step_s = dt
+            else:
+                dt_sum += dt
+                dt_n += 1
+            state["steps"] += 1
+            losses.append((start_step + state["steps"], loss))
+            if ckpt_every and state["steps"] % ckpt_every == 0 \
+                    and state["steps"] < steps_to_run:
+                step_abs = start_step + state["steps"]
+                save_checkpoint(spec["ckpt_path"],
+                                {"params": params, "opt": opt},
+                                {"step": step_abs, "loss": loss})
+                # the ack flushes pending loss records: every step at or
+                # below a durable checkpoint is then recorded parent-side,
+                # so a later crash loses no trajectory (steps PAST the
+                # checkpoint are replayed from it on resume)
+                send_with_losses({"msg": "ckpt", "step": step_abs})
+        step_abs = start_step + state["steps"]
+        save_checkpoint(spec["ckpt_path"], {"params": params, "opt": opt},
+                        {"step": step_abs, "loss": loss})
     send_with_losses({"msg": "ckpt", "step": step_abs})
     stop.set()
     send({"msg": "exit", "steps": state["steps"], "preempted": preempted,
-          "losses": losses, "compile_s": compile_s,
+          "losses": losses,
+          "compile_s": tracing.compile_seconds(seg.tree_counts),
+          "first_step_s": first_step_s, "spans": tracing.span_totals(seg),
           "measured_step_s": (dt_sum / dt_n) if dt_n else None})
     conn.close()

@@ -128,9 +128,12 @@ def test_local_backend_trains_schedule_for_real(tmp_path):
         # the loss trajectory is real numbers from real training
         assert all(np.isfinite(loss) for _, loss in st["losses"])
         assert os.path.exists(tmp_path / f"{j.name}.npz")
-        # compile time is kept out of the measured step rate
+        # the first step (program load) is kept out of the measured
+        # step rate; the segment's compile seconds come from its spans
         seg = st["segments"][0]
+        assert seg["first_step_s"] > seg["measured_step_s"]
         assert seg["compile_s"] > seg["measured_step_s"]
+        assert seg["spans"]["step.dispatch"]["n"] == seg["steps"]
     assert be.observed, "measured step times must reach the feedback dict"
     for v in be.observed.values():
         assert 0 < v < 10
