@@ -197,7 +197,6 @@ class LocalJaxBackend(ExecutionBackend):
         self.resume = resume
         self.observed: Dict[Tuple, float] = {}
         self.job_stats: Dict[str, dict] = {}
-        self._built_cache: Dict[Tuple, object] = {}
 
     # ------------------------------------------------------------- setup
     def bind(self, jobs, profiles, cluster: ClusterSpec) -> None:
@@ -235,21 +234,13 @@ class LocalJaxBackend(ExecutionBackend):
         self.job_stats.clear()
 
     def _built_job(self, job: Job, technique, devices: List):
-        """Build (or reuse) the executable for one (job, technique,
-        device-slice) choice.  Reuse keeps a job relaunched onto the
-        SAME choice from paying the JIT compile twice; a changed
-        assignment — the usual reason for a restart — still compiles
-        for real."""
+        """The executable for one (job, technique, device-slice) choice.
+        Jobs of one model, plan and device slice share one program
+        (``parallelism.build``): only the first of them traces, lowers
+        and loads it in this process."""
         from ..parallelism.build import BuiltJob
-        key = (job.name, technique.name, tuple(id(d) for d in devices))
-        with self._lock:
-            built = self._built_cache.get(key)
-        if built is None:
-            plan = technique.plan(job.cfg, len(devices))
-            built = BuiltJob(job.cfg, plan, job.opt_cfg, devices=devices)
-            with self._lock:
-                self._built_cache[key] = built
-        return built
+        plan = technique.plan(job.cfg, len(devices))
+        return BuiltJob(job.cfg, plan, job.opt_cfg, devices=devices)
 
     # ------------------------------------------------------------- clock
     def now(self) -> float:

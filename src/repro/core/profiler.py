@@ -268,10 +268,9 @@ class TrialRunner:
         self._lock = threading.Lock()
         self._cache: Dict[Tuple[str, str, int, str, str], Profile] = {}
         # one compile per ⟨shape-identical job, technique, mesh shape⟩:
-        # empirical trials reuse the BuiltJob (jit cache follows the
-        # step fn), and every analytic/roofline consumer reuses the
-        # lowered executable + its parsed HLO analysis
-        self._built_cache: Dict[Tuple, BuiltJob] = {}
+        # every analytic/roofline consumer reuses the lowered executable
+        # + its parsed HLO analysis (empirical trials share the step
+        # program itself through parallelism.build)
         self._compile_cache: Dict[Tuple, object] = {}
         self._analysis_cache: Dict[Tuple, Dict[str, float]] = {}
         # per-device-class roofline calibration (persisted in the cache)
@@ -741,17 +740,10 @@ class TrialRunner:
                 job.seq_len, technique, tuple(mesh_shape))
 
     def _built_job(self, job: Job, plan: Plan) -> BuiltJob:
-        """Memoized BuiltJob per shape key: shape-identical jobs share
-        one mesh, one set of shardings and one step function."""
-        key = self._shape_key(job, plan.technique, plan.mesh_shape)
-        with self._lock:
-            built = self._built_cache.get(key)
-        if built is None:
-            built = BuiltJob(job.cfg, plan, job.opt_cfg,
-                             devices=jax.devices()[:plan.n_devices])
-            with self._lock:
-                self._built_cache.setdefault(key, built)
-        return built
+        """The job's BuiltJob; shape-identical jobs share its program
+        (``parallelism.build``)."""
+        return BuiltJob(job.cfg, plan, job.opt_cfg,
+                        devices=jax.devices()[:plan.n_devices])
 
     def _compiled_step(self, job: Job, plan: Plan, args=None):
         """Memoized ``lower().compile()`` of the real step per

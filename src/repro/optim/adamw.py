@@ -5,6 +5,7 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 @dataclasses.dataclass(frozen=True)
@@ -20,11 +21,25 @@ class AdamWConfig:
     total_steps: int = 10000
 
 
+# a job's own settings, which enter its step as float32 operands; the other
+# fields shape the program, so jobs that differ only in these share one
+HYPERPARAMS = ("lr", "weight_decay", "warmup_steps", "total_steps")
+
+
+def split_hyperparams(cfg: AdamWConfig):
+    """``(static, hyper)``: ``cfg`` with its hyperparameters taken out
+    (None), which keys a shared step program, and the hyperparameters as
+    float32 scalars, the operands that program takes in their place."""
+    static = dataclasses.replace(cfg, **dict.fromkeys(HYPERPARAMS))
+    return static, {k: np.float32(getattr(cfg, k)) for k in HYPERPARAMS}
+
+
 def lr_at(cfg: AdamWConfig, step):
     step = step.astype(jnp.float32) if hasattr(step, "astype") else float(step)
-    warm = jnp.minimum(1.0, (step + 1) / max(cfg.warmup_steps, 1))
+    warm = jnp.minimum(1.0, (step + 1) / jnp.maximum(cfg.warmup_steps, 1))
     frac = jnp.clip((step - cfg.warmup_steps)
-                    / max(cfg.total_steps - cfg.warmup_steps, 1), 0.0, 1.0)
+                    / jnp.maximum(cfg.total_steps - cfg.warmup_steps, 1),
+                    0.0, 1.0)
     if cfg.schedule == "cosine":
         decay = 0.5 * (1.0 + jnp.cos(jnp.pi * frac))
     elif cfg.schedule == "linear":

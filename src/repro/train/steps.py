@@ -7,6 +7,7 @@ codec labels; VLM masks the patch prefix), AdamW update, MoE aux loss.
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Optional
 
 import jax
@@ -48,6 +49,10 @@ def make_train_step(cfg: ModelConfig, opt_cfg: AdamWConfig, *,
                     microbatches: int = 1, loss_fn=None):
     """Returns train_step(params, opt_state, batch) -> (params, opt, metrics).
 
+    The step takes an optional fourth argument ``hyper``: the optimizer
+    fields named in ``adamw.HYPERPARAMS`` as traced operands, in place of
+    ``opt_cfg``'s constants (``parallelism.build`` shares one program so).
+
     microbatches > 1 accumulates gradients over batch slices (gradient
     accumulation; the GPipe technique instead passes its own pipelined
     ``loss_fn`` and keeps microbatches=1 here).
@@ -57,7 +62,7 @@ def make_train_step(cfg: ModelConfig, opt_cfg: AdamWConfig, *,
         def loss_fn(params, batch):
             return lm_loss(params, cfg, batch, opts=opts, remat=remat)
 
-    def train_step(params, opt_state, batch):
+    def train_step(params, opt_state, batch, hyper=None):
         if microbatches == 1:
             grads, metrics = jax.grad(
                 lambda p: loss_fn(p, batch), has_aux=True)(params)
@@ -79,8 +84,10 @@ def make_train_step(cfg: ModelConfig, opt_cfg: AdamWConfig, *,
             grads, ms = jax.lax.scan(body, zero, mbatch)
             grads = jax.tree.map(lambda g: g / microbatches, grads)
             metrics = jax.tree.map(lambda x: x.mean(), ms)
+        ocfg = opt_cfg if hyper is None else \
+            dataclasses.replace(opt_cfg, **hyper)
         new_params, new_opt, opt_metrics = adamw_update(
-            opt_cfg, params, grads, opt_state)
+            ocfg, params, grads, opt_state)
         metrics.update(opt_metrics)
         return new_params, new_opt, metrics
 

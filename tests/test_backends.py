@@ -122,18 +122,26 @@ def test_local_backend_trains_schedule_for_real(tmp_path):
     assert {g.job for g in res.gantt if g.kind == "run"} == \
         {j.name for j in jobs}
     assert res.makespan_s > 0
+    first = min((g for g in res.gantt if g.kind == "run"),
+                key=lambda g: g.start_s).job
     for j in jobs:
         st = res.stats[j.name]
         assert sum(s["steps"] for s in st["segments"]) == j.total_steps
         # the loss trajectory is real numbers from real training
         assert all(np.isfinite(loss) for _, loss in st["losses"])
         assert os.path.exists(tmp_path / f"{j.name}.npz")
-        # the first step (program load) is kept out of the measured
-        # step rate; the segment's compile seconds come from its spans
         seg = st["segments"][0]
-        assert seg["first_step_s"] > seg["measured_step_s"]
-        assert seg["compile_s"] > seg["measured_step_s"]
         assert seg["spans"]["step.dispatch"]["n"] == seg["steps"]
+    # the first job's first step (program load) is kept out of the
+    # measured step rate; the segment's compile seconds come from its
+    # spans.  The other jobs share its program and load nothing.
+    seg = res.stats[first]["segments"][0]
+    assert seg["first_step_s"] > seg["measured_step_s"]
+    assert seg["compile_s"] > seg["measured_step_s"]
+    for j in jobs:
+        if j.name != first:
+            assert res.stats[j.name]["segments"][0]["compile_s"] < \
+                seg["compile_s"] / 10
     assert be.observed, "measured step times must reach the feedback dict"
     for v in be.observed.values():
         assert 0 < v < 10
